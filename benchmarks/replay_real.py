@@ -42,6 +42,7 @@ import json
 import os
 import time
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import calibration, scenarios
 from repro.core.providers import get as get_provider
 from repro.core.resources import NETWORK_OVERHEAD_S
@@ -252,6 +253,7 @@ def main(argv=None) -> int:
     ap.add_argument("--out", default=os.path.join("artifacts",
                                                   "replay_report.json"))
     args = ap.parse_args(argv)
+    enable_compile_cache()
     report = replay(args.scenario, stack_name=args.stack, scale=args.scale)
     os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
     with open(args.out, "w") as f:

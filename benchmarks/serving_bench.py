@@ -47,6 +47,7 @@ import time
 
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs.registry import ARCHS
 
 ARCH = "deepseek-7b"
@@ -225,6 +226,7 @@ def main(argv=None) -> int:
                     help="allowed fractional regression vs --baseline "
                          "(default 0.30; CI uses 0.50)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     result = run_bench(tiny=args.tiny, trials=args.trials)
     os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)

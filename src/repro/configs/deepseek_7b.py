@@ -14,6 +14,24 @@ SMOKE = CONFIG.replace(
     param_dtype="float32", compute_dtype="float32",
 )
 
+# One-chip cut (TPU v5e, 16 GB HBM, 15.75 GB of it usable by a program).
+#   source:   arXiv:2401.02954, Table 2 (DeepSeek LLM 7B): 30 layers,
+#             d_model 4096, 32 heads x 128 (MHA), d_ff 11008, vocab 102400.
+#   kept:     every published width, and bfloat16 weights and compute.
+#   reduced:  num_layers 30 -> 16.  All 30 layers are 13.82 GB of weights;
+#             one decode step of 4 slots x 1024 positions then needs
+#             16.62 GB (compile-only rehearsal for v5e).  At 16 layers the
+#             weights are 8.16 GB, and 4 slots x 1024 positions of cache
+#             are 1.07 GB, which leaves room for the decode temporaries
+#             (about twice the cache), the prefill output and the
+#             admission scatter that is live beside the cache.
+#   stands for: a 2-stage pipeline deployment; the 14 absent layers would
+#             be the second chip's stage.  With fewer layers per request the
+#             host's share of each step is larger than in that deployment.
+ONE_CHIP = CONFIG.replace(name="deepseek-7b-1chip", num_layers=16)
+ONE_CHIP_SLOTS = 4
+ONE_CHIP_MAX_SEQ = 1024
+
 SPEC = ArchSpec(
     arch_id="deepseek-7b", config=CONFIG, smoke=SMOKE,
     source="arXiv:2401.02954 (DeepSeek LLM 7B)",

@@ -49,6 +49,7 @@ import warnings
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.function import Handler, batch_rel_cost, normalize_batch_curve
 from repro.models import cnn
 from repro.models.common import ModelConfig, param_bytes
@@ -395,6 +396,7 @@ def main(argv=None) -> int:
     ap.add_argument("--force", action="store_true",
                     help="discard any existing cache and re-measure")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     cache = calibrate(args.path, args.force, models=args.models)
     print(f"calibration cache: {args.path or default_cal_path()}")
     print(f"host: {cache['host']}")

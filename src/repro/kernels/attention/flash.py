@@ -6,6 +6,12 @@ KV streamed in (BK, hd) tiles along the innermost (sequential) grid axis with
 running (m, l, acc) scratch carries — the standard TPU flash schedule with
 MXU-aligned 128x128 tiles.
 
+The kernel works on a heads-major (B, H, S, hd) layout so that every block's
+last two dims are (sequence tile, head_dim): the TPU lowering needs those to
+be (8k, 128k) or the full array dims, which a (1, bq, 1, hd) block over a
+(B, S, H, hd) array is not.  ``flash_attention`` takes and returns the
+model's (B, S, H, hd) layout and transposes at the boundary.
+
 Grid: (B, H, S/BQ, S/BK), KV axis innermost/sequential.
 """
 from __future__ import annotations
@@ -17,11 +23,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-
 NEG_INF = -1e30
 BQ = 128
 BK = 128
+LANES = 128   # the running max / sum live lane-broadcast in (bq, 128) tiles
 
 
 def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
@@ -35,9 +40,9 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, :, 0, :].astype(jnp.float32)           # (bq, hd)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)           # (bk, hd)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
+    q = q_ref[0, 0]                                      # (bq, hd)
+    k = k_ref[0, 0]                                      # (bk, hd)
+    v = v_ref[0, 0]
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
@@ -49,20 +54,21 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         mask &= (q_pos - k_pos) < window
     s = jnp.where(mask, s, NEG_INF)
 
-    m_prev = m_ref[...]                                  # (bq, 1)
-    m_cur = jnp.max(s, axis=1, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_cur)
+    m_prev = m_ref[:, :1]                                # (bq, 1)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
     p = jnp.exp(s - m_new)                               # (bq, bk)
     alpha = jnp.exp(m_prev - m_new)                      # (bq, 1)
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+    l_new = l_ref[:, :1] * alpha + jnp.sum(p, axis=1, keepdims=True)
     acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    m_ref[...] = m_new
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+    l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
 
     @pl.when(ki == nk - 1)
     def _finish():
-        l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, :, 0, :] = (acc_ref[...] / l).astype(o_ref.dtype)
+        l = jnp.maximum(l_ref[:, :1], 1e-30)
+        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("window", "bq", "bk", "interpret"))
@@ -77,27 +83,29 @@ def flash_attention(q, k, v, *, window: int = 0, bq: int = BQ, bk: int = BK,
     assert s % bq == 0 and s % bk == 0, (s, bq, bk)
     nq, nk = s // bq, s // bk
     scale = hd ** -0.5
+    qt, kt, vt = (jnp.swapaxes(x, 1, 2) for x in (q, k, v))   # (B,H,S,hd)
 
     kernel = functools.partial(_kernel, scale=scale, window=window,
                                bq=bq, bk=bk, nk=nk)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid=(b, h, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, bq, 1, hd), lambda bi, hi, qi, ki: (bi, qi, hi, 0)),
-            pl.BlockSpec((1, bk, 1, hd), lambda bi, hi, qi, ki: (bi, ki, hi // g, 0)),
-            pl.BlockSpec((1, bk, 1, hd), lambda bi, hi, qi, ki: (bi, ki, hi // g, 0)),
+            pl.BlockSpec((1, 1, bq, hd), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
+            pl.BlockSpec((1, 1, bk, hd), lambda bi, hi, qi, ki: (bi, hi // g, ki, 0)),
+            pl.BlockSpec((1, 1, bk, hd), lambda bi, hi, qi, ki: (bi, hi // g, ki, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bq, 1, hd),
-                               lambda bi, hi, qi, ki: (bi, qi, hi, 0)),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_specs=pl.BlockSpec((1, 1, bq, hd),
+                               lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
+        out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, LANES), jnp.float32),
+            pltpu.VMEM((bq, LANES), jnp.float32),
             pltpu.VMEM((bq, hd), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
-    )(q, k, v)
+    )(qt, kt, vt)
+    return jnp.swapaxes(out, 1, 2)

@@ -27,10 +27,12 @@ def main():
                     help="also run the measured engine through the platform")
     args = ap.parse_args()
 
+    from repro.compile_cache import enable_compile_cache
     from repro.configs.registry import get
     from repro.serving.batcher import Batcher, PendingRequest
     from repro.serving.engine import InferenceEngine
 
+    enable_compile_cache()
     spec = get(args.arch)
     cfg = spec.smoke if args.smoke else spec.config
     eng = InferenceEngine(cfg, max_cache=args.prompt + args.n_new + 8)

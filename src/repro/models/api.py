@@ -34,6 +34,17 @@ def init_params(rng, cfg: ModelConfig):
     return module_for(cfg).init_params(rng, cfg)
 
 
+def build_params(cfg: ModelConfig, seed: int = 0, shardings=None):
+    """Seeded params built on the device under one jit, each leaf written
+    straight into its target sharding (a pytree of ``Sharding``s; None puts
+    everything on the default device).  Traced, the float32 draw, scale and
+    cast fuse into the leaf's own dtype, so no float32 copy of a whole
+    layer stack is ever materialised — which eager init would do, and which
+    at full depth alone can overrun HBM."""
+    return jax.jit(lambda: init_params(jax.random.PRNGKey(seed), cfg),
+                   out_shardings=shardings)()
+
+
 def abstract_params(cfg: ModelConfig, seed: int = 0):
     """ShapeDtypeStruct pytree of the params — no allocation (for dry-runs)."""
     return jax.eval_shape(lambda: init_params(jax.random.PRNGKey(seed), cfg))

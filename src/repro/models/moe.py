@@ -25,8 +25,6 @@ import math
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.pallas_compat import shard_map_compat
-
 from .common import ModelConfig, activation, dense_init
 
 DEFAULT_GROUP = 4096
@@ -157,7 +155,7 @@ def _moe_shard_map(p, x, cfg: ModelConfig, mesh, group_size: int):
                                  cfg, group_size, e0=e0)
         return jax.lax.psum(y.reshape(bl, s, d), "model")
 
-    y = shard_map_compat(
+    y = jax.shard_map(
         body, mesh=mesh,
         in_specs=(xspec, P(None, None), wspec["wi"], wspec["wu"], wspec["wd"]),
         out_specs=xspec, check_vma=False)(

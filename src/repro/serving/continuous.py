@@ -74,7 +74,7 @@ class ContinuousServer:
         self.cfg = cfg
         self.slots = slots
         self.max_seq = max_seq
-        self.params = api.init_params(jax.random.PRNGKey(seed), cfg)
+        self.params = api.build_params(cfg, seed)
         self.cache = api.init_cache(cfg, slots, max_seq)
         # host control plane: deterministic bookkeeping, never syncs device
         self.pos = np.zeros(slots, np.int32)

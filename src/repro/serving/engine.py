@@ -54,7 +54,8 @@ class InferenceEngine:
         self.mesh = mesh
         self.max_cache = max_cache
         t0 = time.perf_counter()
-        self.params = api.init_params(jax.random.PRNGKey(seed), cfg)
+        self.params = jax.block_until_ready(
+            api.build_params(cfg, seed, self._param_shardings()))
         self.load_s = time.perf_counter() - t0
         self._prefill = jax.jit(self._prefill_impl, static_argnames=("cache_len",))
         self._decode = jax.jit(self._decode_impl)
@@ -65,6 +66,16 @@ class InferenceEngine:
             static_argnames=("n_steps", "temperature"))
         self.compiled = False
         self.compile_s = 0.0
+
+    def _param_shardings(self):
+        """Each leaf's place on the mesh (tensor parallel over "model",
+        by ``launch.sharding.param_pspecs``); None without a mesh."""
+        if self.mesh is None:
+            return None
+        from repro.launch import sharding
+        specs = sharding.param_pspecs(api.abstract_params(self.cfg),
+                                      self.cfg, self.mesh)
+        return sharding.to_named(specs, self.mesh)
 
     # ------------------------------------------------------------------
     def _prefill_impl(self, params, inputs, cache_len, last_pos=None):

@@ -31,7 +31,7 @@ def train(cfg: ModelConfig, *, steps: int = 100, batch: int = 8, seq: int = 64,
           ckpt_path: str = "", num_micro: int = 1, verbose: bool = True) -> TrainReport:
     opt = AdamW(learning_rate=cosine_schedule(lr, warmup=max(steps // 10, 1),
                                               total=steps))
-    params = api.init_params(jax.random.PRNGKey(seed), cfg)
+    params = api.build_params(cfg, seed)
     opt_state = opt.init(params)
     with shardctx.use_mesh(mesh):
         step_fn = jax.jit(make_train_step(cfg, opt, num_micro=num_micro,

@@ -41,7 +41,8 @@ rng = jax.random.PRNGKey(0)
 p = M.moe_init(rng, cfg)
 x = jax.random.normal(rng, (4, 8, 32))
 y_local, aux_local = M.moe_apply(p, x, cfg)        # no mesh installed
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 2), ("data", "model"))
 with shardctx.use_mesh(mesh):
     y_sm, aux_sm = jax.jit(lambda p, x: M.moe_apply(p, x, cfg))(p, x)
 np.testing.assert_allclose(np.asarray(y_local), np.asarray(y_sm),
@@ -71,7 +72,8 @@ rng = jax.random.PRNGKey(1)
 p = M.moe_init(rng, cfg)
 x = jax.random.normal(rng, (2, 8, 32))
 y_local, _ = M.moe_apply(p, x, cfg)
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 2), ("data", "model"))
 with shardctx.use_mesh(mesh):
     y_sm, _ = jax.jit(lambda p, x: M.moe_apply(p, x, cfg))(p, x)
 np.testing.assert_allclose(np.asarray(y_local), np.asarray(y_sm),
@@ -89,7 +91,8 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax
 from repro.launch.dryrun import run_pair
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 rec = run_pair("deepseek-7b", "train_4k", multi_pod=True, out_dir="",
                verbose=False, mesh=mesh)
 assert rec["axes"] == ["pod", "data", "model"]
@@ -105,7 +108,8 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax
 from repro.launch.dryrun import run_pair
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 2), ("data", "model"))
 rec = run_pair("mistral-nemo-12b", "decode_32k", multi_pod=False,
                out_dir="", verbose=False, mesh=mesh, int8=True)
 assert rec["int8"] is True
@@ -132,7 +136,8 @@ opt = AdamW(learning_rate=1e-3)
 batch = {"tokens": jnp.ones((4, 16), jnp.int32),
          "labels": jnp.ones((4, 16), jnp.int32)}
 p1, _, m1 = jax.jit(make_train_step(cfg, opt))(params, opt.init(params), batch)
-mesh = jax.make_mesh((2, 1), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 1), ("data", "model"))
 from repro.launch import sharding
 pspecs = sharding.param_pspecs(api.abstract_params(cfg), cfg, mesh)
 p_sh = sharding.to_named(pspecs, mesh)

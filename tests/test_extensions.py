@@ -90,7 +90,8 @@ def test_shardctx_noop_without_mesh():
 
 def test_shardctx_constrains_with_mesh():
     from repro import shardctx
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1, 1), ("data", "model"))
     with shardctx.use_mesh(mesh):
         x = jnp.ones((4, 8))
         y = shardctx.constrain_batch(x)          # axis size 1: no constraint

@@ -1,10 +1,11 @@
 """Decode fast path: fused-scan/bucketing/scatter parity + recompile pins.
 
-The golden token streams below were captured on the pre-fast-path per-token
-loop implementations (exact-length prefill, per-step server loop, per-block
-pool writes).  Every fast path must reproduce them bit-for-bit — these pins
-are the contract that the perf work in DESIGN.md §4 changed *nothing* about
-what the models emit.
+What the fast paths emit is checked against the cache-free ``forward`` pass
+over the prompt plus the generated tokens (``repro.serving.logit_check``),
+not against token streams recorded on one JAX build: those broke when JAX
+changed how ``jax.random`` derives keys (``jax_threefry_partitionable``
+became the default), which draws different random weights from the same
+seed and so changes every stream from its very first token.
 """
 import jax
 import jax.numpy as jnp
@@ -12,6 +13,7 @@ import numpy as np
 
 from repro.configs.registry import ARCHS
 from repro.models import api
+from repro.serving import logit_check
 from repro.serving.continuous import ContinuousServer, Request, _chunks
 from repro.serving.engine import InferenceEngine, bucket_len
 from repro.serving.kvcache import PagedPool
@@ -20,25 +22,25 @@ from repro.serving.sampler import sample_token
 CFG = ARCHS["deepseek-7b"].smoke
 MOE = ARCHS["granite-moe-3b-a800m"].smoke
 
-# captured on the pre-PR per-token loop (engine seed=0, max_cache=48,
-# prompt [3,1,4,1,5,9,2,6], n_new=6)
-ENGINE_GOLDEN = [468, 252, 367, 168, 503, 367]
-ENGINE_TEMP_GOLDEN = [259, 477, 193, 213, 206, 34]       # temperature=0.8 seed=7
+# Logit tolerance for float32 smoke configs.  The cached and cache-free
+# paths differ only in float32 rounding (about 3e-6 here, logits of std ~1);
+# bfloat16 arithmetic anywhere on the path would move them by ~1e-2.
+TOL = 1e-4
 
-# captured on the pre-PR per-step ContinuousServer (setup mirrors
-# test_continuous._requests: 7 reqs, 3 slots, max_seq=48, n_new=5)
-CONT_GOLDEN = {0: [171, 285, 491, 55, 4], 1: [121, 256, 206, 316, 167],
-               2: [164, 145, 229, 94, 105], 3: [409, 88, 88, 88, 88],
-               4: [343, 343, 343, 343, 343], 5: [233, 102, 102, 102, 397],
-               6: [118, 447, 200, 296, 296]}
+# scheduling facts of the 7-request, 3-slot, n_new=5 run below: they follow
+# from the prompt lengths and budgets alone, not from token values
 CONT_STEPS = 12
 CONT_ORDER = [0, 1, 2, 3, 4, 5, 6]
 CONT_IN_FLIGHT = [4, 4, 4, 8, 8, 8, 12]
 
-# MoE stays on exact-length prefill (routing is length-sensitive) but runs
-# the same fused decode; captured pre-PR (4 reqs, 2 slots, max_seq=24)
-MOE_GOLDEN = {0: [116, 8, 300, 80], 1: [140, 417, 365, 284],
-              2: [227, 51, 226, 106], 3: [289, 407, 225, 390]}
+
+def _assert_matches_forward(params, cfg, prompt, tokens, noise=None,
+                            temperature=1.0):
+    """Every emitted token is the forward pass's best (plus the sampling
+    noise, when sampled) to within TOL."""
+    logits = logit_check.generated_logits(params, cfg, prompt, tokens)
+    m = logit_check.token_margins(logits / temperature, tokens, noise)
+    assert m.max() <= TOL / temperature, (tokens, m)
 
 
 def _cont_requests(n, seed=0, n_new=5):
@@ -55,12 +57,27 @@ def _cont_requests(n, seed=0, n_new=5):
 # ----------------------------------------------------------------------
 
 def test_engine_scan_matches_pre_fast_path_golden():
+    """The fused scan's greedy and sampled streams agree with the forward
+    pass; a sampled token is held to the same rule after adding the Gumbel
+    draw of the key that ``generate`` used at that step."""
     eng = InferenceEngine(CFG, seed=0, max_cache=48)
-    prompt = jnp.asarray([[3, 1, 4, 1, 5, 9, 2, 6]], jnp.int32)
-    res = eng.generate(prompt, 6)
-    assert [int(t) for t in np.asarray(res.tokens[0])] == ENGINE_GOLDEN
-    res_t = eng.generate(prompt, 6, temperature=0.8, seed=7)
-    assert [int(t) for t in np.asarray(res_t.tokens[0])] == ENGINE_TEMP_GOLDEN
+    prompt = [3, 1, 4, 1, 5, 9, 2, 6]
+    res = eng.generate(jnp.asarray([prompt], jnp.int32), 6)
+    _assert_matches_forward(eng.params, CFG, prompt,
+                            [int(t) for t in np.asarray(res.tokens[0])])
+    temp = 0.8
+    res_t = eng.generate(jnp.asarray([prompt], jnp.int32), 6,
+                         temperature=temp, seed=7)
+    rng = jax.random.PRNGKey(7)
+    keys = [rng]                    # first token: the seed key itself
+    for _ in range(5):              # then one split per scanned step
+        rng, sub = jax.random.split(rng)
+        keys.append(sub)
+    noise = np.stack([np.asarray(jax.random.gumbel(
+        k, (1, CFG.vocab_size), jnp.float32))[0] for k in keys])
+    _assert_matches_forward(eng.params, CFG, prompt,
+                            [int(t) for t in np.asarray(res_t.tokens[0])],
+                            noise=noise, temperature=temp)
 
 
 def test_engine_scan_matches_stream_loop():
@@ -93,8 +110,10 @@ def test_engine_bucketing_hits_compile_cache():
 
 def test_bucketed_prefill_last_logits_bit_exact():
     """Right-padding a dense prompt to its bucket and reading logits at
-    ``len-1`` is bit-identical to the exact-length prefill (causal masking:
-    pad tokens only influence positions after themselves)."""
+    ``len-1`` gives the exact-length prefill's logits (causal masking: pad
+    tokens only influence positions after themselves).  Equal up to float32
+    rounding, not bit for bit: the padded matmuls have other shapes, so XLA
+    may sum in another order (observed difference ~1e-6)."""
     params = api.init_params(jax.random.PRNGKey(0), CFG)
     prompt = jnp.asarray([[3, 1, 4, 1, 5, 9, 2, 6, 5, 3]], jnp.int32)  # s=10
     s = prompt.shape[1]
@@ -102,7 +121,8 @@ def test_bucketed_prefill_last_logits_bit_exact():
     padded = jnp.pad(prompt, [(0, 0), (0, bucket_len(s) - s)])
     bucketed, _ = api.prefill(params, {"tokens": padded}, CFG, cache_len=32,
                               last_pos=jnp.int32(s - 1))
-    np.testing.assert_array_equal(np.asarray(exact), np.asarray(bucketed))
+    np.testing.assert_allclose(np.asarray(exact), np.asarray(bucketed),
+                               rtol=0, atol=TOL)
 
 
 # ----------------------------------------------------------------------
@@ -110,27 +130,40 @@ def test_bucketed_prefill_last_logits_bit_exact():
 # ----------------------------------------------------------------------
 
 def test_continuous_matches_pre_fast_path_golden():
+    reqs = _cont_requests(7)
     srv = ContinuousServer(CFG, slots=3, max_seq=48, seed=0)
-    for r in _cont_requests(7):
+    for r in reqs:
         srv.submit(r)
     done = srv.run()
-    assert {c.rid: c.tokens for c in done} == CONT_GOLDEN
+    for c in done:
+        assert len(c.tokens) == 5
+        _assert_matches_forward(srv.params, CFG, reqs[c.rid].prompt, c.tokens)
     assert srv.steps == CONT_STEPS
     assert [c.rid for c in done] == CONT_ORDER
     assert [c.steps_in_flight for c in done] == CONT_IN_FLIGHT
 
 
 def test_continuous_moe_matches_golden():
+    """MoE through exact-length prefills and the fused decode.  Capacity is
+    raised so that no token is dropped: with drops, routing depends on how
+    many tokens share a group, and a whole-sequence forward pass is then no
+    reference for prefill-then-decode."""
+    moe = MOE.replace(moe_capacity_factor=MOE.num_experts
+                      / MOE.num_experts_per_tok)
     rng = np.random.default_rng(3)
     reqs = [Request(rid=i,
-                    prompt=rng.integers(0, MOE.vocab_size,
+                    prompt=rng.integers(0, moe.vocab_size,
                                         size=int(rng.integers(3, 8))).tolist(),
                     n_new=4)
             for i in range(4)]
-    srv = ContinuousServer(MOE, slots=2, max_seq=24, seed=0)
+    srv = ContinuousServer(moe, slots=2, max_seq=24, seed=0)
     for r in reqs:
         srv.submit(r)
-    assert {c.rid: c.tokens for c in srv.run()} == MOE_GOLDEN
+    done = srv.run()
+    assert sorted(c.rid for c in done) == [0, 1, 2, 3]
+    for c in done:
+        assert len(c.tokens) == 4
+        _assert_matches_forward(srv.params, moe, reqs[c.rid].prompt, c.tokens)
 
 
 def test_continuous_fused_matches_per_step():
@@ -249,3 +282,18 @@ def test_batcher_per_request_budgets():
             for i, rid in enumerate(batch.rids)}
     for i, n in enumerate(asks):
         assert outs[i].shape == (n,)       # nobody billed for the batch max
+
+
+def test_cached_logits_match_forward():
+    """Teacher-forced prefill + per-row decode through the cache gives the
+    forward pass's logits at every generated position, for requests of
+    different lengths sharing one batch."""
+    params = api.build_params(CFG, 0)
+    reqs = _cont_requests(3, seed=5, n_new=6)
+    gens = [[(7 * i + j) % CFG.vocab_size for j in range(6)]
+            for i in range(3)]
+    cached = logit_check.cached_logits(params, CFG,
+                                       [r.prompt for r in reqs], gens, 32)
+    for r, g, c in zip(reqs, gens, cached):
+        ref = logit_check.generated_logits(params, CFG, r.prompt, g)
+        np.testing.assert_allclose(c, ref, rtol=0, atol=TOL)

@@ -108,7 +108,8 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, json
 from repro.launch.dryrun import run_pair
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 2), ("data", "model"))
 rec = run_pair("{arch}", "{shape}", multi_pod=False, out_dir="", verbose=False,
                mesh=mesh)
 assert rec["roofline"]["bound_time_s"] > 0
@@ -152,7 +153,8 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, json
 from repro.launch.dryrun import comms_summary
-mesh = jax.make_mesh((1, 4), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((1, 4), ("data", "model"))
 s = comms_summary("{arch}", "decode_32k", mesh=mesh)
 print("COMMS_JSON", json.dumps(s))
 """
