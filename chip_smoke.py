@@ -35,22 +35,24 @@ KERNEL_SHAPES = {
 }
 
 # Kernel tolerances, as a share of the reference's largest magnitude.
-# bf16 kernels (flash attention / decode) round their output, and the
-# probabilities fed to the PV matmul, to 8 mantissa bits (2^-8 relative);
-# 2^-6 leaves 4x for accumulation order, and a kernel computing in fp8
-# (2^-4) would fail it.  wkv6 runs in float32 throughout: its error is
-# summation order (~1e-6); 1e-4 would catch any bf16 step (2^-8).
+# bf16 kernels (flash attention / decode) take bf16 q, k, v, keep the
+# softmax and the PV product in float32, and round their output to 8
+# mantissa bits (2^-8 relative); 2^-6 leaves 4x for accumulation order, and
+# a kernel computing in fp8 (2^-4) would fail it.  wkv6 runs in float32
+# throughout: its error is summation order (~1e-6); 1e-4 would catch any
+# bf16 step (2^-8).
 BF16_KERNEL_TOL = 2.0 ** -6
 F32_KERNEL_TOL = 1e-4
 
 # Served-logit tolerances for bfloat16 configs, as a share of the std of
-# the forward pass's logits (about 1 with these random weights).  The
-# cached and cache-free paths round different intermediate sums to bf16
-# (8 mantissa bits); a 1-ulp flip early in a layer stack spreads to every
+# the reference logits (about 1 with these random weights).  Two paths that
+# compute the same logits differently (through the cache or not, on one
+# chip or tensor parallel) round different intermediate sums to bf16 (8
+# mantissa bits); a 1-ulp flip early in a layer stack spreads to every
 # later position.  Mean |diff| <= 2^-6 holds for bf16 rounding noise and
-# fails for fp8-level noise (2^-4); the largest of ~3M compared logits is
-# allowed 2^-3.  A served token may trail the forward pass's best logit by
-# at most twice the largest per-logit difference, 2^-2.
+# fails for fp8-level noise (2^-4); the largest of ~10M compared logits is
+# allowed 2^-3.  A served token may trail the reference's best logit by at
+# most twice the largest per-logit difference, 2^-2.
 MEAN_TOL = 2.0 ** -6
 MAX_TOL = 2.0 ** -3
 MARGIN_TOL = 2.0 ** -2
@@ -141,27 +143,27 @@ def kernel_phase(seed: int, shapes: dict = KERNEL_SHAPES,
 
 
 # ----------------------------------------------------------------------
-# served logits vs the cache-free forward pass
+# logits of one path against another's
 # ----------------------------------------------------------------------
 
-def logit_agreement(tag: str, params, cfg, prompts, gens, max_seq: int,
-                    ref_params=None) -> dict:
-    """Teacher-forced logits through the cache (``params``) against the
-    forward pass (``ref_params``, default the same) at every generated
-    position, and each generated token's margin under the forward pass."""
+def logit_agreement(tag: str, rows) -> dict:
+    """``rows``: per request (tokens, logits, reference logits), the logits
+    (len(tokens), V), the row that chose each token.  Checks their mean and
+    largest |diff| and how far each token trails the reference's best, all
+    as shares of the reference logits' std."""
     from repro.serving import logit_check
-    ref_params = params if ref_params is None else ref_params
-    cached = logit_check.cached_logits(params, cfg, prompts, gens, max_seq)
     diffs, stds, margins = [], [], []
-    for p, g, c in zip(prompts, gens, cached):
-        ref = logit_check.generated_logits(ref_params, cfg, p, g)
-        check(np.isfinite(c).all() and np.isfinite(ref).all(),
+    for toks, got, ref in rows:
+        got, ref = np.asarray(got, np.float32), np.asarray(ref, np.float32)
+        check(got.shape == ref.shape == (len(toks), ref.shape[-1]),
+              f"{tag}: logits {got.shape} vs {ref.shape}")
+        check(np.isfinite(got).all() and np.isfinite(ref).all(),
               f"{tag}: non-finite logits")
-        diffs.append(np.abs(c - ref))
+        diffs.append(np.abs(got - ref))
         stds.append(float(ref.std()))
-        margins.append(logit_check.token_margins(ref, g))
+        margins.append(logit_check.token_margins(ref, toks))
     std = float(np.mean(stds))
-    out = {"positions": int(sum(len(g) for g in gens)),
+    out = {"positions": int(sum(len(d) for d in diffs)),
            "logit_std": std,
            "max_diff": float(max(d.max() for d in diffs)),
            "mean_diff": float(np.mean([d.mean() for d in diffs])),
@@ -185,12 +187,15 @@ def peak_hbm(device) -> int | None:
 
 def server_phase(cfg, *, slots: int, max_seq: int, seed: int,
                  n_requests: int = 8, prompt_range=(100, 501),
-                 n_new: int = 32, n_checked: int = 2) -> dict:
-    """Serve ``n_requests`` seeded prompts twice through ContinuousServer:
-    the first drain compiles every prefill bucket and decode chunk the
-    traffic needs, the second runs warm and must emit the same tokens."""
+                 n_new: int = 32) -> dict:
+    """Serve ``n_requests`` seeded prompts through ContinuousServer three
+    times: the first drain compiles every prefill bucket and decode chunk
+    the traffic needs, the second runs warm and must emit the same tokens,
+    and the third keeps the logits the server chose each token from, which
+    must agree with the cache-free forward pass."""
     import jax
 
+    from repro.serving import logit_check
     from repro.serving.continuous import ContinuousServer, Request
 
     t0 = time.perf_counter()
@@ -208,16 +213,18 @@ def server_phase(cfg, *, slots: int, max_seq: int, seed: int,
             srv.submit(Request(rid=i, prompt=list(p), n_new=n_new))
         t = time.perf_counter()
         done = srv.run()
-        return time.perf_counter() - t, {c.rid: c.tokens for c in done}
+        return time.perf_counter() - t, {c.rid: c for c in done}
 
     cold_s, first = drain()
     compiles = srv.compile_stats()
     warm_s, second = drain()
     check(srv.compile_stats() == compiles, "the warm drain recompiled")
-    check(first == second, "the same requests gave different tokens")
-    tokens_out = sum(len(t) for t in second.values())
+    tokens = {i: c.tokens for i, c in second.items()}
+    check({i: c.tokens for i, c in first.items()} == tokens,
+          "the same requests gave different tokens")
+    tokens_out = sum(len(t) for t in tokens.values())
     check(tokens_out == n_requests * n_new, f"tokens out {tokens_out}")
-    check(all(0 <= t < cfg.vocab_size for ts in second.values() for t in ts),
+    check(all(0 <= t < cfg.vocab_size for ts in tokens.values() for t in ts),
           "token id out of range")
     res = {"init_s": init_s, "cold_drain_s": cold_s, "warm_drain_s": warm_s,
            "compile_s": cold_s - warm_s, "tokens_out": tokens_out,
@@ -226,12 +233,15 @@ def server_phase(cfg, *, slots: int, max_seq: int, seed: int,
            "peak_hbm_bytes": peak_hbm(jax.devices()[0])}
     log(f"server {cfg.name}: {res}")
 
-    # the shortest and the longest prompt, through the cache and without
-    order = sorted(range(n_requests), key=lambda i: len(prompts[i]))
-    picked = order[:n_checked // 2] + order[len(order) - (n_checked + 1) // 2:]
-    res["agreement"] = logit_agreement(
-        "served vs forward", srv.params, cfg, [prompts[i] for i in picked],
-        [second[i] for i in picked], max_seq)
+    # the same admission and scatter; the fused step also returns logits
+    srv.keep_logits = True
+    _, kept = drain()
+    check({i: c.tokens for i, c in kept.items()} == tokens,
+          "keeping the logits changed the served tokens")
+    res["agreement"] = logit_agreement("served vs forward", [
+        (c.tokens, c.logits,
+         logit_check.generated_logits(srv.params, cfg, prompts[i], c.tokens))
+        for i, c in sorted(kept.items())])
     return res
 
 
@@ -253,9 +263,10 @@ def four_chip_phase(devices, seed: int, full_cfg=None, cut_layers: int = 8,
                     batch: int = 4, prompt_len: int = 128,
                     n_new: int = 16) -> dict:
     """mistral-nemo-12b at full size, tensor parallel over a (1, 4) mesh
-    through ``InferenceEngine(mesh=...)``; then the same model cut to
-    ``cut_layers`` (which one chip holds) on four chips and on one, whose
-    logits must agree."""
+    through ``InferenceEngine(mesh=...)``, its served logits against its
+    forward pass; then the same model cut to ``cut_layers`` (which one chip
+    holds) on four chips and on one: served logits against served logits,
+    forward against forward."""
     import jax
     import jax.numpy as jnp
 
@@ -294,32 +305,45 @@ def four_chip_phase(devices, seed: int, full_cfg=None, cut_layers: int = 8,
     check(out.shape == (batch, n_new), f"tokens shape {out.shape}")
     res.update(prefill_s=warm.prefill_s, decode_s=warm.decode_s,
                decode_tokens_per_s=warm.tokens_per_s)
-    margins = []                    # as shares of the forward logits' std
-    for p, g in zip(prompts.tolist(), out.tolist()):
-        ref = logit_check.generated_logits(eng.params, cfg, p, g)
-        check(np.isfinite(ref).all(), "non-finite forward logits")
-        margins.append(float(logit_check.token_margins(ref, g).max()
-                             / ref.std()))
-    res["max_margin_full"] = max(margins)
     log(f"tp{len(devices)} {cfg.name} generate: cold "
         f"{res['cold_generate_s']!r}s, warm prefill {warm.prefill_s!r}s "
-        f"decode {warm.decode_s!r}s ({warm.tokens_per_s!r} tok/s); token "
-        f"margins under the forward pass / logit std {margins} "
-        f"(limit {MARGIN_TOL!r})")
-    check(max(margins) <= MARGIN_TOL, "served token margin (full size)")
-    del eng, cold, warm
+        f"decode {warm.decode_s!r}s ({warm.tokens_per_s!r} tok/s)")
+    kept = eng.generate(toks, n_new, keep_logits=True)
+    check(np.array_equal(np.asarray(kept.tokens), out),
+          "keeping the logits changed the served tokens")
+    res["served_vs_forward"] = logit_agreement(
+        f"tp{len(devices)} {cfg.name} served vs forward", [
+            (g, lg, logit_check.generated_logits(eng.params, cfg, p, g))
+            for p, g, lg in zip(prompts.tolist(), out.tolist(),
+                                np.asarray(kept.logits))])
+    del eng, cold, warm, kept
     gc.collect()
 
-    # 4 chips vs 1 at a depth one chip holds
+    # 4 chips vs 1 at a depth one chip holds: the served (cached) logits of
+    # each, and the forward pass of each over the four-chip tokens
     cut = cfg.replace(num_layers=cut_layers)
     tp = InferenceEngine(cut, seed=seed, mesh=mesh, max_cache=max_cache)
-    gen = np.asarray(tp.generate(toks, n_new).tokens)
     single = InferenceEngine(cut, seed=seed, max_cache=max_cache)
     check(set(device_bytes(single.params)) == {jax.devices()[0]},
           "the one-chip engine is not on one chip")
-    res["tp_vs_one"] = logit_agreement(
-        f"tp{len(devices)} vs 1 chip ({cut_layers} layers)", tp.params, cut,
-        prompts.tolist(), gen.tolist(), max_cache, ref_params=single.params)
+    tp_out = tp.generate(toks, n_new, keep_logits=True)
+    one_out = single.generate(toks, n_new, keep_logits=True)
+    tp_toks, one_toks = (np.asarray(r.tokens) for r in (tp_out, one_out))
+    rows = []
+    for b_ in range(batch):
+        # the streams are comparable up to and including the first token
+        # they chose differently
+        differ = np.flatnonzero(tp_toks[b_] != one_toks[b_])
+        n = differ[0] + 1 if len(differ) else n_new
+        rows.append((tp_toks[b_, :n], np.asarray(tp_out.logits[b_, :n]),
+                     np.asarray(one_out.logits[b_, :n])))
+    res["tp_vs_one_served"] = logit_agreement(
+        f"tp{len(devices)} vs 1 chip served ({cut_layers} layers)", rows)
+    res["tp_vs_one_forward"] = logit_agreement(
+        f"tp{len(devices)} vs 1 chip forward ({cut_layers} layers)", [
+            (g, logit_check.generated_logits(tp.params, cut, p, g),
+             logit_check.generated_logits(single.params, cut, p, g))
+            for p, g in zip(prompts.tolist(), tp_toks.tolist())])
     res["peak_hbm_bytes"] = {str(d): peak_hbm(d) for d in devices}
     log(f"peak HBM per device {res['peak_hbm_bytes']}")
     return res

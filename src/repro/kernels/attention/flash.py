@@ -40,6 +40,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
+    # q.k in the input dtype (exact products, float32 sums); softmax and
+    # p.v in float32
     q = q_ref[0, 0]                                      # (bq, hd)
     k = k_ref[0, 0]                                      # (bk, hd)
     v = v_ref[0, 0]
@@ -60,7 +62,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
     alpha = jnp.exp(m_prev - m_new)                      # (bq, 1)
     l_new = l_ref[:, :1] * alpha + jnp.sum(p, axis=1, keepdims=True)
     acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        p, v.astype(jnp.float32), (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
     m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
     l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)

@@ -51,6 +51,8 @@ def _kernel(q_ref, k_ref, v_ref, valid_ref, o_ref, m_ref, l_ref, acc_ref, *,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
+    # q.k in the input dtype (exact products, float32 sums); softmax and
+    # p.v in float32
     valid = valid_ref[...] != 0                          # (1, bk)
     for j in range(hb):                                  # static: hb is small
         q = q_ref[0, j]                                  # (g, hd)
@@ -65,7 +67,7 @@ def _kernel(q_ref, k_ref, v_ref, valid_ref, o_ref, m_ref, l_ref, acc_ref, *,
         alpha = jnp.exp(m_prev - m_new)
         l_new = l_ref[j][:, :1] * alpha + jnp.sum(p, axis=1, keepdims=True)
         acc_ref[j] = acc_ref[j] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            p, v.astype(jnp.float32), (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_ref[j] = jnp.broadcast_to(m_new, m_ref.shape[1:])
         l_ref[j] = jnp.broadcast_to(l_new, l_ref.shape[1:])

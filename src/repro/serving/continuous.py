@@ -64,16 +64,22 @@ class Completion:
     rid: int
     tokens: list
     steps_in_flight: int
+    # (len(tokens), V) float32: the logits that chose each token, when the
+    # server keeps them (``keep_logits``)
+    logits: Optional[np.ndarray] = None
 
 
 class ContinuousServer:
     def __init__(self, cfg: ModelConfig, *, slots: int = 4, max_seq: int = 128,
-                 seed: int = 0):
+                 seed: int = 0, keep_logits: bool = False):
         assert cfg.family in ("dense", "moe", "vlm"), \
             "continuous batching drives the transformer KV-cache layout"
         self.cfg = cfg
         self.slots = slots
         self.max_seq = max_seq
+        # also bring back the logits row that chose each token — the same
+        # prefill and scatter, and the fused step with one more output
+        self.keep_logits = keep_logits
         self.params = api.build_params(cfg, seed)
         self.cache = api.init_cache(cfg, slots, max_seq)
         # host control plane: deterministic bookkeeping, never syncs device
@@ -86,6 +92,7 @@ class ContinuousServer:
         self._tok_dev = jnp.zeros((slots,), jnp.int32)
         self._pos_dev = jnp.zeros((slots,), jnp.int32)
         self.out: dict[int, list] = {}
+        self.out_logits: dict[int, list] = {}
         self.queue: deque[Request] = deque()
         self._done: list[Completion] = []
         self._steps = 0
@@ -102,13 +109,15 @@ class ContinuousServer:
                     new.astype(full.dtype)), cache, rows),
             donate_argnums=(0,))
         self._fused = jax.jit(self._fused_impl, donate_argnums=(1, 2, 3),
-                              static_argnames=("n_steps",))
+                              static_argnames=("n_steps", "keep_logits"))
 
     # ------------------------------------------------------------------
-    def _fused_impl(self, params, cache, tok, pos, active, *, n_steps: int):
+    def _fused_impl(self, params, cache, tok, pos, active, *, n_steps: int,
+                    keep_logits: bool = False):
         """n_steps fused decode steps under one jit.  Rows outside
         ``active`` keep their carry frozen (same stale inputs the per-step
-        loop fed them), so the token stream is bit-identical to stepping."""
+        loop fed them), so the token stream is bit-identical to stepping.
+        ``keep_logits`` also returns each step's (slots, V) float32 logits."""
         def body(carry, _):
             cache, tok, pos = carry
             logits, cache = api.decode_step(params, cache, tok, pos,
@@ -116,7 +125,8 @@ class ContinuousServer:
             nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             tok = jnp.where(active, nxt, tok)
             pos = jnp.where(active, pos + 1, pos)
-            return (cache, tok, pos), nxt
+            out = (nxt, logits.astype(jnp.float32)) if keep_logits else nxt
+            return (cache, tok, pos), out
         (cache, tok, pos), toks = jax.lax.scan(
             body, (cache, tok, pos), None, length=n_steps)
         return cache, tok, pos, toks          # toks: (n_steps, slots)
@@ -199,6 +209,8 @@ class ContinuousServer:
             self.remaining[s] = req.n_new - 1
             self.last_tok[s] = tok
             self.out[req.rid] = [tok]
+            if self.keep_logits:
+                self.out_logits[req.rid] = [np.asarray(logits[j], np.float32)]
             if req.n_new == 1:
                 self._finish(s)
         # resync the device compute state from the host mirrors (H2D only)
@@ -207,29 +219,38 @@ class ContinuousServer:
 
     def _finish(self, s: int):
         rid = self.rid[s]
-        self._done.append(Completion(rid, list(self.out[rid]), self._steps))
+        kept = self.out_logits.pop(rid, None)
+        self._done.append(Completion(
+            rid, list(self.out[rid]), self._steps,
+            None if kept is None else np.stack(kept)))
         self.active[s] = False
         self.rid[s] = -1
 
     # ------------------------------------------------------------------
-    def _run_chunk(self, n_steps: int) -> np.ndarray:
+    def _run_chunk(self, n_steps: int):
         """n_steps fused steps on device; returns the (n_steps, slots)
-        token block — the single device→host transfer."""
-        self.cache, self._tok_dev, self._pos_dev, toks = self._fused(
+        token block — the single device→host transfer — and, when kept,
+        the (n_steps, slots, V) logits."""
+        self.cache, self._tok_dev, self._pos_dev, out = self._fused(
             self.params, self.cache, self._tok_dev, self._pos_dev,
-            jnp.asarray(self.active), n_steps=n_steps)
+            jnp.asarray(self.active), n_steps=n_steps,
+            keep_logits=self.keep_logits)
         self._steps += n_steps
-        return np.asarray(toks)
+        if self.keep_logits:
+            return np.asarray(out[0]), np.asarray(out[1])
+        return np.asarray(out), None
 
-    def _settle(self, toks: np.ndarray):
+    def _settle(self, toks: np.ndarray, logits=None):
         """Apply a token block to the host control plane; finish slots
         whose budget (or cache) ran out."""
-        for row in toks:
+        for i, row in enumerate(toks):
             for s in range(self.slots):
                 if not self.active[s]:
                     continue
                 t = int(row[s])
                 self.out[self.rid[s]].append(t)
+                if logits is not None:
+                    self.out_logits[self.rid[s]].append(logits[i, s])
                 self.pos[s] += 1
                 self.last_tok[s] = t
                 self.remaining[s] -= 1
@@ -238,7 +259,7 @@ class ContinuousServer:
 
     def step(self):
         """One fused decode step across all active slots."""
-        self._settle(self._run_chunk(1))
+        self._settle(*self._run_chunk(1))
 
     # ------------------------------------------------------------------
     def run(self) -> list:
@@ -257,7 +278,7 @@ class ContinuousServer:
                         self.max_seq - 1 - int(self.pos[s]))
                     for s in range(self.slots) if self.active[s])
             for c in _chunks(max(1, k)):
-                self._settle(self._run_chunk(c))
+                self._settle(*self._run_chunk(c))
         done, self._done = self._done, []
         return done
 

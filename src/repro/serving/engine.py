@@ -45,6 +45,8 @@ class GenerateResult:
     decode_s: float
     tokens_per_s: float
     token_walls: Optional[list] = None   # per-token decode walls (stream path)
+    # (B, n_new, V) float32: the logits that chose each token, when kept
+    logits: Optional["jnp.ndarray"] = None
 
 
 class InferenceEngine:
@@ -63,7 +65,7 @@ class InferenceEngine:
         # the cache argument is donated so XLA aliases it in place
         self._decode_scan = jax.jit(
             self._decode_scan_impl, donate_argnums=(1,),
-            static_argnames=("n_steps", "temperature"))
+            static_argnames=("n_steps", "temperature", "keep_logits"))
         self.compiled = False
         self.compile_s = 0.0
 
@@ -88,16 +90,19 @@ class InferenceEngine:
             return api.decode_step(params, cache, token, pos, self.cfg)
 
     def _decode_scan_impl(self, params, cache, tok, pos, rng, *,
-                          n_steps: int, temperature: float):
+                          n_steps: int, temperature: float,
+                          keep_logits: bool = False):
         """Fused decode: n_steps of (decode_step -> sample) under one jit.
         The RNG key sequence is bit-identical to the per-token loop's
-        (split once per step; greedy ignores the subkeys entirely)."""
+        (split once per step; greedy ignores the subkeys entirely).
+        ``keep_logits`` also returns each step's (B, V) float32 logits."""
         def body(carry, _):
             cache, tok, pos, rng = carry
             rng, sub = jax.random.split(rng)
             logits, cache = self._decode_impl(params, cache, tok, pos)
             nxt = sample_token(logits, temperature, sub)
-            return (cache, nxt, pos + 1, rng), nxt
+            out = (nxt, logits.astype(jnp.float32)) if keep_logits else nxt
+            return (cache, nxt, pos + 1, rng), out
         (cache, tok, pos, rng), toks = jax.lax.scan(
             body, (cache, tok, pos, rng), None, length=n_steps)
         return toks, cache          # toks: (n_steps, B)
@@ -143,11 +148,13 @@ class InferenceEngine:
 
     # ------------------------------------------------------------------
     def generate(self, tokens: jnp.ndarray, n_new: int, *,
-                 temperature: float = 0.0, seed: int = 0) -> GenerateResult:
+                 temperature: float = 0.0, seed: int = 0,
+                 keep_logits: bool = False) -> GenerateResult:
         """tokens: (B, S) prompt.  Greedy/temperature decoding of n_new.
 
         Fused path: one prefill dispatch + one scanned decode dispatch +
-        one device→host transfer, regardless of n_new."""
+        one device→host transfer, regardless of n_new.  ``keep_logits``
+        also returns the logits that chose each token."""
         b, s = tokens.shape
         s_pad, cache_len = self._prefill_shapes(s, n_new)
         if s_pad > s:
@@ -164,10 +171,16 @@ class InferenceEngine:
         rng = jax.random.PRNGKey(seed)
         tok = sample_token(logits, temperature, rng)
         t0 = time.perf_counter()
+        kept = logits.astype(jnp.float32)[:, None] if keep_logits else None
         if n_new > 1:
             rest, _cache = self._decode_scan(
                 self.params, cache, tok, jnp.int32(s), rng,
-                n_steps=n_new - 1, temperature=float(temperature))
+                n_steps=n_new - 1, temperature=float(temperature),
+                keep_logits=keep_logits)
+            if keep_logits:
+                rest, rest_logits = rest
+                kept = jnp.concatenate(
+                    [kept, jnp.swapaxes(rest_logits, 0, 1)], axis=1)
             toks = jnp.concatenate([tok[:, None], rest.T], axis=1)
         else:
             toks = tok[:, None]
@@ -175,7 +188,8 @@ class InferenceEngine:
         decode_s = time.perf_counter() - t0
         tps = (b * max(n_new - 1, 1)) / max(decode_s, 1e-9)
         return GenerateResult(tokens=toks, prefill_s=prefill_s,
-                              decode_s=decode_s, tokens_per_s=tps)
+                              decode_s=decode_s, tokens_per_s=tps,
+                              logits=kept)
 
     def generate_stream(self, tokens: jnp.ndarray, n_new: int, *,
                         temperature: float = 0.0,

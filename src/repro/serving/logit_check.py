@@ -1,10 +1,12 @@
 """Served output checked against the cache-free forward pass.
 
 The served path computes each token through the KV cache: a (bucketed)
-prefill, then one decode step per token.  ``forward`` over the prompt plus
-the generated tokens computes every position's logits in one pass, with no
-cache and no batching across requests.  The two must agree up to
-the rounding of the compute dtype.  Logits are compared, never token ids
+prefill, then one decode step per token; ``ContinuousServer`` and
+``InferenceEngine.generate`` bring back the logits that chose each token when
+asked to keep them.  ``forward`` over the prompt plus the generated tokens
+computes every position's logits in one pass, with no cache and no batching
+across requests.  The two must agree up to the rounding of the compute
+dtype.  Logits are compared, never token ids
 alone: with random weights the top two logits can sit within rounding of
 each other, so a token is held only to being within a stated logit
 tolerance of the forward pass's best.
@@ -31,54 +33,21 @@ def _forward(params, tokens, cfg: ModelConfig):
 def generated_logits(params, cfg: ModelConfig, prompt, tokens) -> np.ndarray:
     """(len(tokens), V) float32: the cache-free forward pass's logits at the
     positions that chose each generated token (the prompt's last position
-    onwards).  The sequence is right-padded to a power-of-two length
-    (causal, so the pad changes no real position) and sliced on the device,
-    so only those rows come back."""
+    onwards).  A dense model's sequence is right-padded to a power-of-two
+    length (causal, so the pad changes no real position); an MoE sequence
+    stays exact, since pad tokens would take expert capacity.  The rows are
+    sliced on the device, so only those come back."""
     seq = list(prompt) + list(tokens[:-1])
-    toks = np.zeros((1, bucket_len(len(seq))), np.int32)
+    n = bucket_len(len(seq)) if cfg.family == "dense" else len(seq)
+    toks = np.zeros((1, n), np.int32)
     toks[0, :len(seq)] = seq
     logits = _forward(params, jnp.asarray(toks), cfg)
     return np.asarray(logits[0, len(prompt) - 1:len(seq)])
 
 
-def token_margins(logits: np.ndarray, tokens, noise=None) -> np.ndarray:
-    """How far each chosen token's score falls below its row's best score
-    (0 where they agree).  ``noise`` adds the Gumbel draws of a sampled
-    stream, so sampled tokens are held to the same rule as greedy ones."""
+def token_margins(logits: np.ndarray, tokens) -> np.ndarray:
+    """How far each chosen token's logit falls below its row's best logit
+    (0 where they agree)."""
     score = np.asarray(logits, np.float64)
-    if noise is not None:
-        score = score + np.asarray(noise, np.float64)
     picked = score[np.arange(len(tokens)), np.asarray(tokens)]
     return score.max(axis=-1) - picked
-
-
-def cached_logits(params, cfg: ModelConfig, prompts, gens,
-                  max_seq: int) -> list:
-    """Teacher-forced logits through the KV cache: one bucketed prefill of
-    all prompts, then per-row-position decode steps fed the generated tokens
-    — the same ``prefill(last_pos=...)`` and vector-``pos``
-    ``decode_step`` the continuous server runs.  Returns, per request, the
-    (len(gen), V) float32 logits that chose each generated token."""
-    b = len(prompts)
-    n = min(bucket_len(max(len(p) for p in prompts)), max_seq)
-    toks = np.zeros((b, n), np.int32)
-    for i, p in enumerate(prompts):
-        toks[i, :len(p)] = p
-    last = np.asarray([len(p) - 1 for p in prompts], np.int32)
-    prefill = jax.jit(lambda p, t, lp: api.prefill(
-        p, {"tokens": t}, cfg, cache_len=max_seq, last_pos=lp))
-    decode = jax.jit(lambda p, c, t, pos: api.decode_step(p, c, t, pos, cfg),
-                     donate_argnums=(1,))
-    logits, cache = prefill(params, jnp.asarray(toks), jnp.asarray(last))
-    steps = [np.asarray(logits, np.float32)]
-    n_steps = max(len(g) for g in gens) - 1
-    gen = np.zeros((b, n_steps + 1), np.int32)
-    for i, g in enumerate(gens):
-        gen[i, :len(g)] = g
-    pos = last + 1
-    for j in range(n_steps):
-        logits, cache = decode(params, cache, jnp.asarray(gen[:, j]),
-                               jnp.asarray(pos + j))
-        steps.append(np.asarray(logits, np.float32))
-    stacked = np.stack(steps, axis=1)                   # (B, steps, V)
-    return [stacked[i, :len(g)] for i, g in enumerate(gens)]

@@ -54,7 +54,7 @@ def test_server_phase_smoke():
                                   n_requests=4, prompt_range=(5, 30),
                                   n_new=6)
     assert res["tokens_out"] == 24
-    assert res["agreement"]["positions"] == 12
+    assert res["agreement"]["positions"] == 24     # every served token
     assert res["agreement"]["max_diff"] < 1e-4     # float32 smoke config
 
 
@@ -69,7 +69,10 @@ res = chip_smoke.four_chip_phase(jax.devices()[:4], 0,
                                  full_cfg=mistral_nemo_12b.SMOKE,
                                  cut_layers=1, batch=2, prompt_len=8, n_new=4)
 assert len(res["bytes_per_device"]) == 4
-print("FOUR_OK", res["tp_vs_one"]["max_diff"])
+assert res["served_vs_forward"]["positions"] == 8
+assert res["tp_vs_one_forward"]["positions"] == 8
+assert 2 <= res["tp_vs_one_served"]["positions"] <= 8
+print("FOUR_OK", res["tp_vs_one_served"]["max_diff"])
 """
     env = dict(os.environ, PYTHONPATH=os.pathsep.join(
         [os.path.join(REPO, "src"), REPO]))

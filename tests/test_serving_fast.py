@@ -2,10 +2,11 @@
 
 What the fast paths emit is checked against the cache-free ``forward`` pass
 over the prompt plus the generated tokens (``repro.serving.logit_check``),
-not against token streams recorded on one JAX build: those broke when JAX
-changed how ``jax.random`` derives keys (``jax_threefry_partitionable``
-became the default), which draws different random weights from the same
-seed and so changes every stream from its very first token.
+not against token streams recorded on one JAX build.  Those were recorded
+with ``jax_threefry_partitionable`` off; JAX 0.9 turns it on by default,
+which draws other random weights from the same seed and so changes every
+stream from its very first token (with the flag off again, every recorded
+engine, continuous and MoE stream is reproduced exactly).
 """
 import jax
 import jax.numpy as jnp
@@ -34,12 +35,12 @@ CONT_ORDER = [0, 1, 2, 3, 4, 5, 6]
 CONT_IN_FLIGHT = [4, 4, 4, 8, 8, 8, 12]
 
 
-def _assert_matches_forward(params, cfg, prompt, tokens, noise=None,
+def _assert_matches_forward(params, cfg, prompt, tokens, noise=0.0,
                             temperature=1.0):
     """Every emitted token is the forward pass's best (plus the sampling
     noise, when sampled) to within TOL."""
     logits = logit_check.generated_logits(params, cfg, prompt, tokens)
-    m = logit_check.token_margins(logits / temperature, tokens, noise)
+    m = logit_check.token_margins(logits / temperature + noise, tokens)
     assert m.max() <= TOL / temperature, (tokens, m)
 
 
@@ -143,27 +144,68 @@ def test_continuous_matches_pre_fast_path_golden():
     assert [c.steps_in_flight for c in done] == CONT_IN_FLIGHT
 
 
-def test_continuous_moe_matches_golden():
-    """MoE through exact-length prefills and the fused decode.  Capacity is
-    raised so that no token is dropped: with drops, routing depends on how
-    many tokens share a group, and a whole-sequence forward pass is then no
-    reference for prefill-then-decode."""
-    moe = MOE.replace(moe_capacity_factor=MOE.num_experts
-                      / MOE.num_experts_per_tok)
+def _moe_requests():
     rng = np.random.default_rng(3)
-    reqs = [Request(rid=i,
-                    prompt=rng.integers(0, moe.vocab_size,
+    return [Request(rid=i,
+                    prompt=rng.integers(0, MOE.vocab_size,
                                         size=int(rng.integers(3, 8))).tolist(),
                     n_new=4)
             for i in range(4)]
-    srv = ContinuousServer(moe, slots=2, max_seq=24, seed=0)
+
+
+def _moe_served_vs_single(srv):
+    """Serve the MoE requests through ``srv``; return the largest logit
+    difference from one-request, exact-length ``InferenceEngine`` runs, and
+    whether every token agrees.
+
+    At the served capacity factor (1.25) an exact-length prefill drops
+    tokens, so routing depends on which tokens share a group: a forward pass
+    over prompt plus output is no reference past the first token.  A
+    one-request run groups the prefill exactly as the server must, and a
+    decode step of one or two rows cannot overflow an expert (each row picks
+    2 distinct experts of 4, capacity >= 1 per row), so the two must agree
+    everywhere.  The first token is also held to ``forward`` over exactly
+    its prompt, which the prefill groups the same way."""
+    reqs = _moe_requests()
     for r in reqs:
         srv.submit(r)
-    done = srv.run()
-    assert sorted(c.rid for c in done) == [0, 1, 2, 3]
-    for c in done:
-        assert len(c.tokens) == 4
-        _assert_matches_forward(srv.params, moe, reqs[c.rid].prompt, c.tokens)
+    done = {c.rid: c for c in srv.run()}
+    assert sorted(done) == [0, 1, 2, 3]
+    eng = InferenceEngine(MOE, seed=0, max_cache=srv.max_seq)
+    worst, same = 0.0, True
+    for r in reqs:
+        c = done[r.rid]
+        assert len(c.tokens) == c.logits.shape[0] == 4
+        one = eng.generate(jnp.asarray([r.prompt], jnp.int32), r.n_new,
+                           keep_logits=True)
+        first = logit_check.generated_logits(srv.params, MOE, r.prompt,
+                                             c.tokens[:1])
+        worst = max(worst,
+                    float(np.abs(c.logits - np.asarray(one.logits[0])).max()),
+                    float(np.abs(c.logits[:1] - first).max()))
+        same &= c.tokens == [int(t) for t in np.asarray(one.tokens[0])]
+    return worst, same
+
+
+def test_continuous_moe_matches_golden():
+    """MoE through exact-length prefills and the fused decode, at the served
+    capacity factor, agrees with one request served alone."""
+    assert MOE.moe_capacity_factor == 1.25
+    srv = ContinuousServer(MOE, slots=2, max_seq=24, seed=0,
+                           keep_logits=True)
+    worst, same = _moe_served_vs_single(srv)
+    assert worst <= TOL and same
+
+
+def test_continuous_moe_bucketed_prefill_is_caught():
+    """The check above has teeth: a server that padded MoE prompts to a
+    shared bucket (as it does dense ones) lets pad tokens and the other
+    request take expert capacity, and misses it by far more than TOL."""
+    srv = ContinuousServer(MOE, slots=2, max_seq=24, seed=0,
+                           keep_logits=True)
+    srv._prefill_exact = srv._prefill_bucketed
+    worst, _ = _moe_served_vs_single(srv)
+    assert worst > 1e3 * TOL
 
 
 def test_continuous_fused_matches_per_step():
@@ -285,15 +327,24 @@ def test_batcher_per_request_budgets():
 
 
 def test_cached_logits_match_forward():
-    """Teacher-forced prefill + per-row decode through the cache gives the
-    forward pass's logits at every generated position, for requests of
-    different lengths sharing one batch."""
-    params = api.build_params(CFG, 0)
-    reqs = _cont_requests(3, seed=5, n_new=6)
-    gens = [[(7 * i + j) % CFG.vocab_size for j in range(6)]
-            for i in range(3)]
-    cached = logit_check.cached_logits(params, CFG,
-                                       [r.prompt for r in reqs], gens, 32)
-    for r, g, c in zip(reqs, gens, cached):
-        ref = logit_check.generated_logits(params, CFG, r.prompt, g)
-        np.testing.assert_allclose(c, ref, rtol=0, atol=TOL)
+    """The logits the server chose each token from, through bucketed
+    admission, the slot scatter and the fused chunks, equal the forward
+    pass's at every generated position, for requests of different lengths
+    sharing the slots; keeping them changes no token."""
+    reqs = _cont_requests(5, seed=5, n_new=6)
+    kept = ContinuousServer(CFG, slots=3, max_seq=32, seed=0,
+                            keep_logits=True)
+    plain = ContinuousServer(CFG, slots=3, max_seq=32, seed=0)
+    for r in reqs:
+        kept.submit(r)
+        plain.submit(Request(r.rid, list(r.prompt), r.n_new))
+    done = {c.rid: c for c in kept.run()}
+    plain_done = plain.run()
+    assert {c.rid: c.tokens for c in plain_done} == \
+        {rid: c.tokens for rid, c in done.items()}
+    assert all(c.logits is None for c in plain_done)
+    for r in reqs:
+        c = done[r.rid]
+        ref = logit_check.generated_logits(kept.params, CFG, r.prompt,
+                                           c.tokens)
+        np.testing.assert_allclose(c.logits, ref, rtol=0, atol=TOL)
