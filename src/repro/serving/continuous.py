@@ -37,7 +37,7 @@ import numpy as np
 
 from repro.models import api
 from repro.models.common import ModelConfig
-from repro.serving.engine import bucket_len
+from repro.serving.engine import bucket_len, pin_logits
 
 # fused-step scan chunk cap: step counts decompose into powers of two up to
 # this, so the scan jit compiles at most log2(64)+1 variants ever
@@ -122,6 +122,7 @@ class ContinuousServer:
             cache, tok, pos = carry
             logits, cache = api.decode_step(params, cache, tok, pos,
                                             self.cfg)
+            logits = pin_logits(logits)
             nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             tok = jnp.where(active, nxt, tok)
             pos = jnp.where(active, pos + 1, pos)

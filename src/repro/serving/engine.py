@@ -38,6 +38,16 @@ def bucket_len(n: int) -> int:
     return max(1, 1 << (int(n) - 1).bit_length())
 
 
+def pin_logits(logits):
+    """Write a decode step's logits out whole before a token is picked.
+    Without the barrier XLA fuses the vocabulary matmul into its consumer —
+    the argmax alone, or the argmax and the kept copy with ``keep_logits``
+    — and the two fusions tile the matmul's accumulation differently.  On
+    the TPU that moves a few bf16 logits by one ulp, enough to flip a
+    near-tied argmax, so keeping the logits would change the tokens."""
+    return jax.lax.optimization_barrier(logits)
+
+
 @dataclasses.dataclass
 class GenerateResult:
     tokens: "jnp.ndarray"          # (B, n_new)
@@ -100,6 +110,7 @@ class InferenceEngine:
             cache, tok, pos, rng = carry
             rng, sub = jax.random.split(rng)
             logits, cache = self._decode_impl(params, cache, tok, pos)
+            logits = pin_logits(logits)
             nxt = sample_token(logits, temperature, sub)
             out = (nxt, logits.astype(jnp.float32)) if keep_logits else nxt
             return (cache, nxt, pos + 1, rng), out
