@@ -49,12 +49,16 @@ F32_KERNEL_TOL = 1e-4
 # compute the same logits differently (through the cache or not, on one
 # chip or tensor parallel) round different intermediate sums to bf16 (8
 # mantissa bits); a 1-ulp flip early in a layer stack spreads to every
-# later position.  Mean |diff| <= 2^-6 holds for bf16 rounding noise and
-# fails for fp8-level noise (2^-4); the largest of ~10M compared logits is
-# allowed 2^-3.  A served token may trail the reference's best logit by at
-# most twice the largest per-logit difference, 2^-2.
-MEAN_TOL = 2.0 ** -6
-MAX_TOL = 2.0 ** -3
+# later position, and the flips of independent layers add up: the mean
+# |diff| grows about as sqrt(depth) (on a TPU v5e, 0.0115 at 16 layers and
+# 0.0174 at 40).  Mean |diff| <= 2^-5 holds for bf16 rounding up to ~100
+# layers and fails for fp8 rounding (5 fewer mantissa bits, 32x the noise)
+# or a wrong cache position or mask (differences of a whole std).  The
+# largest of ~10M compared logits has stayed within 7x the mean: 2^-2.  A
+# served token may trail the reference's best logit by at most 2^-2; a
+# wrongly chosen token trails it by a sizeable share of the std.
+MEAN_TOL = 2.0 ** -5
+MAX_TOL = 2.0 ** -2
 MARGIN_TOL = 2.0 ** -2
 
 
