@@ -1,0 +1,22 @@
+"""Published peaks of each chip, keyed by JAX's ``device_kind``.
+
+Source: Google Cloud documentation, "TPU v5e" (per chip: 197 TFLOP/s in
+bfloat16, 393 TOP/s in int8, 16 GB of HBM at 819 GB/s).  A device kind that
+is not in the table is an error, never a default.
+"""
+from __future__ import annotations
+
+_V5E = {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9, "hbm_bytes": 16e9}
+
+PEAKS = {
+    "TPU v5 lite": _V5E,
+    "TPU v5e": _V5E,
+}
+
+
+def peaks(device_kind: str) -> dict:
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no peaks for device kind {device_kind!r}; add its "
+                       "published numbers to bench/lib/peaks.py") from None
