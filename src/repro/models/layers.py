@@ -144,8 +144,9 @@ def attention_decode(p: dict, x: jnp.ndarray, pos: jnp.ndarray,
         q = apply_rope(q, pos[:, None], cfg.rope_theta)
         k = apply_rope(k, pos[:, None], cfg.rope_theta)
         rows = jnp.arange(b)
-        cache_k = cache_k.at[rows, pos].set(k[:, 0].astype(cache_k.dtype))
-        cache_v = cache_v.at[rows, pos].set(v[:, 0].astype(cache_v.dtype))
+        with jax.named_scope("kv_write"):
+            cache_k = cache_k.at[rows, pos].set(k[:, 0].astype(cache_k.dtype))
+            cache_v = cache_v.at[rows, pos].set(v[:, 0].astype(cache_v.dtype))
         kv_pos = jnp.arange(s, dtype=jnp.int32)
         valid = kv_pos[None, :] <= pos[:, None]                # (B,S)
         if win:
@@ -156,10 +157,11 @@ def attention_decode(p: dict, x: jnp.ndarray, pos: jnp.ndarray,
     posv = jnp.full((1,), 0, jnp.int32) + pos
     q = apply_rope(q, posv[None], cfg.rope_theta)
     k = apply_rope(k, posv[None], cfg.rope_theta)
-    cache_k = jax.lax.dynamic_update_slice(cache_k, k.astype(cache_k.dtype),
-                                           (0, pos, 0, 0))
-    cache_v = jax.lax.dynamic_update_slice(cache_v, v.astype(cache_v.dtype),
-                                           (0, pos, 0, 0))
+    with jax.named_scope("kv_write"):
+        cache_k = jax.lax.dynamic_update_slice(
+            cache_k, k.astype(cache_k.dtype), (0, pos, 0, 0))
+        cache_v = jax.lax.dynamic_update_slice(
+            cache_v, v.astype(cache_v.dtype), (0, pos, 0, 0))
     # Windowed decode against a much longer cache: slice just the live band so
     # the attention sweep is O(window), not O(S) — this is what makes
     # long_500k viable for the sliding-window dense variants.

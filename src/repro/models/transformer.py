@@ -162,28 +162,48 @@ def prefill(params, tokens, cfg: ModelConfig, cache_len: int | None = None,
     return last, {"k": ks, "v": vs}
 
 
+# the jax.named_scope names decode_step's operations carry (kv_write is
+# attention_decode's cache write, inside attention)
+DECODE_SCOPES = ("embed", "norm", "attention", "kv_write", "mlp", "moe",
+                 "unembed")
+
+
 def decode_step(params, cache: dict, token: jnp.ndarray, pos: jnp.ndarray,
                 cfg: ModelConfig, *, input_embeds=None):
-    """token: (B,) int32; pos: scalar int32.  -> (logits (B,V), new cache)."""
-    x = (embed(params["embed"], token[:, None], cfg)
-         if input_embeds is None else input_embeds)
-    x = x.astype(cfg.cdt)
+    """token: (B,) int32; pos: scalar int32.  -> (logits (B,V), new cache).
+
+    Each part runs under a ``jax.named_scope`` (``embed``, ``norm``,
+    ``attention``, ``mlp`` or ``moe``, ``unembed``; each sub-layer's
+    residual add inside its scope), so a device trace names the layer an
+    operation belongs to; what the layer scan adds lies under none."""
+    with jax.named_scope("embed"):
+        x = (embed(params["embed"], token[:, None], cfg)
+             if input_embeds is None else input_embeds)
+        x = x.astype(cfg.cdt)
 
     def body(carry, layer):
         from repro import shardctx
         lp, ck, cv = layer
         carry = shardctx.constrain_batch(carry)
-        h = apply_norm(lp["ln1"], carry, cfg.norm)
-        a, nk, nv = attention_decode(lp["attn"], h, pos, ck, cv, cfg)
-        y = carry + a
-        h = apply_norm(lp["ln2"], y, cfg.norm)
+        with jax.named_scope("norm"):
+            h = apply_norm(lp["ln1"], carry, cfg.norm)
+        with jax.named_scope("attention"):
+            a, nk, nv = attention_decode(lp["attn"], h, pos, ck, cv, cfg)
+            y = carry + a
+        with jax.named_scope("norm"):
+            h = apply_norm(lp["ln2"], y, cfg.norm)
         if cfg.is_moe:
-            m, _ = moe_lib.moe_apply(lp["moe"], h, cfg)
+            with jax.named_scope("moe"):
+                m, _ = moe_lib.moe_apply(lp["moe"], h, cfg)
+                y = y + m
         else:
-            m = mlp_apply(lp["mlp"], h, cfg)
-        return y + m, (nk, nv)
+            with jax.named_scope("mlp"):
+                y = y + mlp_apply(lp["mlp"], h, cfg)
+        return y, (nk, nv)
 
     x, (nk, nv) = jax.lax.scan(body, x, (params["layers"], cache["k"], cache["v"]))
-    x = apply_norm(params["final_norm"], x, cfg.norm)
-    logits = unembed(params["embed"], x, cfg)[:, 0]
+    with jax.named_scope("norm"):
+        x = apply_norm(params["final_norm"], x, cfg.norm)
+    with jax.named_scope("unembed"):
+        logits = unembed(params["embed"], x, cfg)[:, 0]
     return logits, {"k": nk, "v": nv}

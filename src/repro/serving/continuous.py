@@ -24,6 +24,13 @@ change real tokens' routing) but still share the per-round scatter.
 Transformer-family models (dense / moe / vlm).  Greedy decoding.
 ``repro.core.calibration`` drives this server to measure per-model
 batch-efficiency curves (fused-step wall time at a pinned slot count).
+
+Instrumentation: host spans (``jax.profiler.TraceAnnotation``, nothing
+unless a profiler runs) named ``serve.admit`` and ``serve.decode``, each
+with children for the phases where the host dispatches, waits for the
+device or does its own bookkeeping; the device programs carry names
+(``jit_admit_prefill``, ``jit_slot_scatter``, ``jit__fused_impl``) and
+``jax.named_scope`` paths; ``counters`` holds the work counts.
 """
 from __future__ import annotations
 
@@ -37,7 +44,12 @@ import numpy as np
 
 from repro.models import api
 from repro.models.common import ModelConfig
+from repro.models.transformer import DECODE_SCOPES
 from repro.serving.engine import bucket_len, pin_logits
+
+span = jax.profiler.TraceAnnotation
+# every jax.named_scope of the fused decode step: the model's, and sample
+SCOPES = (*DECODE_SCOPES, "sample")
 
 # fused-step scan chunk cap: step counts decompose into powers of two up to
 # this, so the scan jit compiles at most log2(64)+1 variants ever
@@ -50,6 +62,23 @@ def _chunks(k: int):
         c = min(MAX_CHUNK, 1 << (k.bit_length() - 1))
         yield c
         k -= c
+
+
+def slot_scatter(cache, rows, idx):
+    """Write the admitted ``rows`` (leading axis layers, then rows) into
+    the cache's slots ``idx``."""
+    return jax.tree_util.tree_map(
+        lambda full, new: full.at[:, idx].set(new.astype(full.dtype)),
+        cache, rows)
+
+
+@dataclasses.dataclass
+class Counters:
+    """Work counts since the server was built: monotone host integers,
+    none of which waits for the device."""
+    prompt_tokens: int = 0         # real prompt tokens admitted
+    prefill_tokens: int = 0        # tokens the prefill computed, padding in
+    decode_steps: int = 0          # fused decode steps
 
 
 @dataclasses.dataclass
@@ -95,19 +124,15 @@ class ContinuousServer:
         self.out_logits: dict[int, list] = {}
         self.queue: deque[Request] = deque()
         self._done: list[Completion] = []
-        self._steps = 0
-        self._prefill = jax.jit(
-            lambda p, t, last_pos, n: api.prefill(p, {"tokens": t}, cfg,
-                                                  cache_len=n,
-                                                  last_pos=last_pos),
-            static_argnames=("n",))
+        self.counters = Counters()
+
+        def admit_prefill(p, t, last_pos, n):
+            return api.prefill(p, {"tokens": t}, cfg, cache_len=n,
+                               last_pos=last_pos)
+        self._prefill = jax.jit(admit_prefill, static_argnames=("n",))
         # one scatter per admission round; the pool-sized cache is donated
         # so XLA writes the admitted rows in place
-        self._scatter = jax.jit(
-            lambda cache, rows, idx: jax.tree_util.tree_map(
-                lambda full, new: full.at[:, idx].set(
-                    new.astype(full.dtype)), cache, rows),
-            donate_argnums=(0,))
+        self._scatter = jax.jit(slot_scatter, donate_argnums=(0,))
         self._fused = jax.jit(self._fused_impl, donate_argnums=(1, 2, 3),
                               static_argnames=("n_steps", "keep_logits"))
 
@@ -123,9 +148,10 @@ class ContinuousServer:
             logits, cache = api.decode_step(params, cache, tok, pos,
                                             self.cfg)
             logits = pin_logits(logits)
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            tok = jnp.where(active, nxt, tok)
-            pos = jnp.where(active, pos + 1, pos)
+            with jax.named_scope("sample"):
+                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                tok = jnp.where(active, nxt, tok)
+                pos = jnp.where(active, pos + 1, pos)
             out = (nxt, logits.astype(jnp.float32)) if keep_logits else nxt
             return (cache, tok, pos), out
         (cache, tok, pos), toks = jax.lax.scan(
@@ -143,7 +169,7 @@ class ContinuousServer:
     @property
     def steps(self) -> int:
         """Fused decode steps taken so far (the throughput denominator)."""
-        return self._steps
+        return self.counters.decode_steps
 
     def prefill_pending(self) -> None:
         """Admit queued requests into free slots (prefill each, copy its
@@ -153,13 +179,18 @@ class ContinuousServer:
         self._admit()
 
     # ------------------------------------------------------------------
+    def _bucket(self, reqs) -> int:
+        """The length a bucketed admission round pads its prompts to."""
+        return min(bucket_len(max(len(r.prompt) for r in reqs)),
+                   self.max_seq)
+
     def _prefill_bucketed(self, reqs):
         """ONE batched prefill for the whole admission round: batch padded
         to the slot count, prompts right-padded to a shared power-of-two
         bucket — so the prefill jit compiles once per bucket."""
         m = len(reqs)
-        bucket = min(bucket_len(max(len(r.prompt) for r in reqs)),
-                     self.max_seq)
+        bucket = self._bucket(reqs)
+        self.counters.prefill_tokens += self.slots * bucket
         toks = np.zeros((self.slots, bucket), np.int32)
         last = np.zeros((self.slots,), np.int32)
         for j, r in enumerate(reqs):
@@ -174,6 +205,7 @@ class ContinuousServer:
         """Per-request exact-length prefills (MoE/VLM: pad tokens shift
         expert routing, so bucketing would change real tokens).  Caches
         still merge into one per-round scatter."""
+        self.counters.prefill_tokens += sum(len(r.prompt) for r in reqs)
         logits, rows = [], []
         for r in reqs:
             lg, pc = self._prefill(
@@ -194,52 +226,68 @@ class ContinuousServer:
             return
         reqs = [self.queue.popleft() for _ in range(m)]
         idx = free[:m]
-        if self.cfg.family == "dense":
-            logits, rows = self._prefill_bucketed(reqs)
-        else:
-            logits, rows = self._prefill_exact(reqs)
-        # one donated slot-scatter per round — not a pool copy per request
-        self.cache = self._scatter(self.cache, rows,
-                                   jnp.asarray(idx, jnp.int32))
-        first = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
-        for j, (s, req) in enumerate(zip(idx, reqs)):
-            tok = int(first[j])
-            self.active[s] = True
-            self.rid[s] = req.rid
-            self.pos[s] = len(req.prompt)
-            self.remaining[s] = req.n_new - 1
-            self.last_tok[s] = tok
-            self.out[req.rid] = [tok]
-            if self.keep_logits:
-                self.out_logits[req.rid] = [np.asarray(logits[j], np.float32)]
-            if req.n_new == 1:
-                self._finish(s)
-        # resync the device compute state from the host mirrors (H2D only)
-        self._tok_dev = jnp.asarray(self.last_tok, jnp.int32)
-        self._pos_dev = jnp.asarray(self.pos, jnp.int32)
+        dense = self.cfg.family == "dense"
+        self.counters.prompt_tokens += sum(len(r.prompt) for r in reqs)
+        # bucket 0: exact-length prefills
+        with span("serve.admit", rids=[r.rid for r in reqs],
+                  bucket=self._bucket(reqs) if dense else 0):
+            with span("serve.admit.prefill"):
+                if dense:
+                    logits, rows = self._prefill_bucketed(reqs)
+                else:
+                    logits, rows = self._prefill_exact(reqs)
+            # one donated slot-scatter per round, not a pool copy per request
+            with span("serve.admit.scatter"):
+                self.cache = self._scatter(self.cache, rows,
+                                           jnp.asarray(idx, jnp.int32))
+            with span("serve.admit.first_token"):
+                first = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
+            for j, (s, req) in enumerate(zip(idx, reqs)):
+                tok = int(first[j])
+                self.active[s] = True
+                self.rid[s] = req.rid
+                self.pos[s] = len(req.prompt)
+                self.remaining[s] = req.n_new - 1
+                self.last_tok[s] = tok
+                self.out[req.rid] = [tok]
+                if self.keep_logits:
+                    self.out_logits[req.rid] = [
+                        np.asarray(logits[j], np.float32)]
+                if req.n_new == 1:
+                    self._finish(s)
+            # resync the device compute state from the host mirrors (H2D)
+            with span("serve.admit.sync"):
+                self._tok_dev = jnp.asarray(self.last_tok, jnp.int32)
+                self._pos_dev = jnp.asarray(self.pos, jnp.int32)
 
     def _finish(self, s: int):
         rid = self.rid[s]
         kept = self.out_logits.pop(rid, None)
         self._done.append(Completion(
-            rid, list(self.out[rid]), self._steps,
+            rid, list(self.out[rid]), self.counters.decode_steps,
             None if kept is None else np.stack(kept)))
         self.active[s] = False
         self.rid[s] = -1
 
     # ------------------------------------------------------------------
-    def _run_chunk(self, n_steps: int):
-        """n_steps fused steps on device; returns the (n_steps, slots)
-        token block — the single device→host transfer — and, when kept,
-        the (n_steps, slots, V) logits."""
-        self.cache, self._tok_dev, self._pos_dev, out = self._fused(
-            self.params, self.cache, self._tok_dev, self._pos_dev,
-            jnp.asarray(self.active), n_steps=n_steps,
-            keep_logits=self.keep_logits)
-        self._steps += n_steps
-        if self.keep_logits:
-            return np.asarray(out[0]), np.asarray(out[1])
-        return np.asarray(out), None
+    def _decode(self, n_steps: int):
+        """n_steps fused steps on device, then the (n_steps, slots) token
+        block — the single device→host transfer — and, when kept, the
+        (n_steps, slots, V) logits, applied to the host control plane."""
+        self.counters.decode_steps += n_steps
+        with span("serve.decode", steps=n_steps, active=self.n_active()):
+            with span("serve.decode.dispatch"):
+                self.cache, self._tok_dev, self._pos_dev, out = self._fused(
+                    self.params, self.cache, self._tok_dev, self._pos_dev,
+                    jnp.asarray(self.active), n_steps=n_steps,
+                    keep_logits=self.keep_logits)
+            with span("serve.decode.fetch"):
+                if self.keep_logits:
+                    toks, logits = np.asarray(out[0]), np.asarray(out[1])
+                else:
+                    toks, logits = np.asarray(out), None
+            with span("serve.decode.settle"):
+                self._settle(toks, logits)
 
     def _settle(self, toks: np.ndarray, logits=None):
         """Apply a token block to the host control plane; finish slots
@@ -260,7 +308,7 @@ class ContinuousServer:
 
     def step(self):
         """One fused decode step across all active slots."""
-        self._settle(*self._run_chunk(1))
+        self._decode(1)
 
     # ------------------------------------------------------------------
     def run(self) -> list:
@@ -279,7 +327,7 @@ class ContinuousServer:
                         self.max_seq - 1 - int(self.pos[s]))
                     for s in range(self.slots) if self.active[s])
             for c in _chunks(max(1, k)):
-                self._settle(*self._run_chunk(c))
+                self._decode(c)
         done, self._done = self._done, []
         return done
 
